@@ -101,7 +101,7 @@ grads = {
 }
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.collectives import shard_map
+from jax import shard_map
 
 
 def sync(g):

@@ -55,6 +55,7 @@ def _site_specs(plan) -> List[Tuple[str, str, int]]:
 def _exercise_one(mesh, sid: str, kind: str, nc: int, n: int):
     """One builder call at ``sid`` with shapes the resolved ``nc``
     divides.  Runs inside the traced function."""
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -87,8 +88,8 @@ def _exercise_one(mesh, sid: str, kind: str, nc: int, n: int):
         def body(gl):
             return C.psum_tree_chunked({"g": gl}, "x", site=sid)["g"]
 
-        return C.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
-                           out_specs=P())(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
+                             out_specs=P())(g)
     if kind == "permute":
         from repro.parallel.pipeline import _chunked_ppermute
 
@@ -100,8 +101,8 @@ def _exercise_one(mesh, sid: str, kind: str, nc: int, n: int):
             return _chunked_ppermute(xl, "x", perm,
                                      num_chunks=rt.num_chunks, site=sid)
 
-        return C.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
-                           out_specs=P("x", None))(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
+                             out_specs=P("x", None))(x)
     raise ValueError(f"no exerciser for comm kind {kind!r}")
 
 

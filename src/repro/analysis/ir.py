@@ -34,11 +34,12 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-# canonical kind -> artifact spellings.  ``psum2`` is the shard_map-body
-# psum on current jax; older traces bind ``psum``.
+# canonical kind -> artifact spellings.  Inside a shard_map body jax 0.9
+# binds ``lax.psum`` as ``psum_invariant``; ``psum``/``psum2`` are its
+# spellings elsewhere.
 COLLECTIVE_OPS: Dict[str, Dict[str, Tuple[str, ...]]] = {
     "allgather": {"hlo": ("all-gather",), "jaxpr": ("all_gather",)},
-    "allreduce": {"hlo": ("all-reduce",), "jaxpr": ("psum", "psum2")},
+    "allreduce": {"hlo": ("all-reduce",), "jaxpr": ("psum", "psum2", "psum_invariant")},
     "reducescatter": {
         "hlo": ("reduce-scatter",),
         "jaxpr": ("reduce_scatter", "psum_scatter"),

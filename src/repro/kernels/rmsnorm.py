@@ -22,7 +22,9 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
                   * s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def rmsnorm_pallas(x, scale, *, eps: float = 1e-5, interpret: bool = True):
+def rmsnorm_pallas(x, scale, *, eps: float = 1e-5, interpret: bool):
+    """RMSNorm over the last axis; ``interpret`` runs the Pallas
+    interpreter (CPU only)."""
     orig_shape = x.shape
     D = x.shape[-1]
     xf = x.reshape(-1, D)
@@ -39,5 +41,6 @@ def rmsnorm_pallas(x, scale, *, eps: float = 1e-5, interpret: bool = True):
         out_specs=pl.BlockSpec((rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xf, scale)
     return out[:T].reshape(orig_shape)

@@ -5,16 +5,41 @@
 A run config is a flat JSON object whose keys mirror the launcher flags
 (``arch``, ``steps``, ``seq``, ``batch``, ``lr``, ``grad_accum``, ``mesh``,
 ``smoke``, ``ckpt``) plus optional ``overrides`` applied to the
-ModelConfig (e.g. {"sliding_window": 8192}).  CLI flags win over file
-values; ``overrides`` compose via ModelConfig.replace.
+ModelConfig (e.g. {"sliding_window": 8192}) and a free-text ``comment``
+(what the run cuts from the published model, and why).  CLI flags win
+over file values; ``overrides`` compose via ModelConfig.replace.
+
+``configure_compile_cache`` is the launchers' one place for JAX's
+persistent compilation cache.
 """
 from __future__ import annotations
 
 import json
+import os
 from typing import Any, Dict
+
+import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig
+
+# the checkout's root: <root>/src/repro/launch/config.py
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def configure_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed path and return
+    it.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache is ``<checkout>/.jax_cache``
+    (git-ignored).  Called from the launchers' ``main()``, never on
+    import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 _LAUNCH_KEYS = ("arch", "steps", "seq", "batch", "lr", "grad_accum",
                 "mesh", "smoke", "ckpt", "log_every")
@@ -23,7 +48,7 @@ _LAUNCH_KEYS = ("arch", "steps", "seq", "batch", "lr", "grad_accum",
 def load_run_config(path: str) -> Dict[str, Any]:
     with open(path) as f:
         raw = json.load(f)
-    unknown = set(raw) - set(_LAUNCH_KEYS) - {"overrides"}
+    unknown = set(raw) - set(_LAUNCH_KEYS) - {"overrides", "comment"}
     if unknown:
         raise ValueError(f"unknown run-config keys: {sorted(unknown)}")
     return raw

@@ -13,12 +13,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat ``jax.make_mesh``: jax >= 0.5 wants explicit
-    axis_types; older jax has no AxisType at all."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in Auto (GSPMD-propagated) mode."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

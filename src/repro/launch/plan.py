@@ -3,9 +3,11 @@
 
 "Co-tune once, deploy the plan": a plan saved by ``session.tune(...)``
 (``plan.save("plan.json")``) — or auto-stored in a ``PlanRepository``
-(``tune(..., repo=...)``) — is loaded at launch, lowered to per-site
-collective runtime knobs via ``core.apply``, and installed process-wide
-(``parallel.collectives.runtime_for``).
+(``tune(..., repo=...)``) — is loaded at launch and lowered to per-site
+collective runtime knobs via ``core.apply``.  The training launcher scopes
+it to the run (``load_tuned_plan(...).applied()``), so one process can run
+with and without it; ``apply_tuned_plan`` and the repository path install
+it process-wide (``parallel.collectives.runtime_for``).
 
 Reach: the knobs apply to every explicit chunked-collective call site —
 ``ring_ag_matmul`` / ``mm_reduce_scatter`` / ``chunked_all_to_all`` /
@@ -35,14 +37,13 @@ from repro.core.extract import (ParallelPlan, extract_decode_workload,
 from repro.core.plan_repo import PlanRepoError, PlanRepository
 from repro.core.session import TunedPlan, workload_fingerprint
 
-__all__ = ["apply_tuned_plan", "parse_parallel", "print_runtime_table",
-           "resolve_plan_repo", "runtime_table"]
+__all__ = ["apply_tuned_plan", "load_tuned_plan", "parse_parallel",
+           "print_runtime_table", "resolve_plan_repo", "runtime_table"]
 
 
-def apply_tuned_plan(path: str, *, expect_arch: Optional[str] = None,
-                     quiet: bool = False) -> Dict:
-    """Load, lower, and install a saved plan; returns the runtime plan
-    (identical to ``TunedPlan.load(path).runtime_plan()``).  When
+def load_tuned_plan(path: str, *, expect_arch: Optional[str] = None,
+                    quiet: bool = False) -> TunedPlan:
+    """Load a saved plan and report what it will install.  When
     ``expect_arch`` is given and does not match the model the plan was
     tuned on, a ``RuntimeWarning`` is emitted (the plan still applies —
     fallback knobs are coarse — but the tuning is unsound for a
@@ -55,8 +56,8 @@ def apply_tuned_plan(path: str, *, expect_arch: Optional[str] = None,
             f"but this launch runs arch {expect_arch!r} — site knobs "
             "may not correspond; re-tune for this model",
             RuntimeWarning, stacklevel=2)
-    rt = activate(plan)
     if not quiet:
+        rt = plan.runtime_plan()
         classes = {k: v for k, v in rt.items() if "." not in k}
         knobs = ", ".join(f"{k}={v.strategy}/x{v.num_chunks}"
                           for k, v in sorted(classes.items()))
@@ -64,7 +65,15 @@ def apply_tuned_plan(path: str, *, expect_arch: Optional[str] = None,
               f"{plan.hardware} (workload {plan.workload}, "
               f"{plan.profile_count} profiles) -> {len(rt)} addressable "
               f"site entries; class fallbacks: {knobs}")
-    return rt
+    return plan
+
+
+def apply_tuned_plan(path: str, *, expect_arch: Optional[str] = None,
+                     quiet: bool = False) -> Dict:
+    """Load, lower, and install a saved plan process-wide; returns the
+    runtime plan (identical to ``TunedPlan.load(path).runtime_plan()``)."""
+    return activate(load_tuned_plan(path, expect_arch=expect_arch,
+                                    quiet=quiet))
 
 
 # ---------------------------------------------------------------------------

@@ -6,23 +6,28 @@ real TPU pods the same flags apply, device count comes from the runtime).
 
     PYTHONPATH=src python -m repro.launch.train --arch h2o-danube-1.8b \
         --smoke --steps 50
+
+``main`` returns ``(params, history)``.  The mesh (``--mesh``) and a
+``--tuned-plan`` are scoped to the call, so one process can run it
+several times, with and without them.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, SyntheticCorpus
-from repro.launch.plan import apply_tuned_plan, resolve_plan_repo
+from repro.launch.config import configure_compile_cache
+from repro.launch.plan import load_tuned_plan, resolve_plan_repo
 from repro.models import model as M
 from repro.optim import adamw
 from repro.parallel import constraints as CT
 from repro.parallel import sharding as SH
 from repro.train import checkpoint
-from repro.train.trainer import TrainConfig, make_train_step, train_loop
+from repro.train.trainer import TrainConfig, train_loop
 
 
 def main(argv=None):
@@ -77,6 +82,7 @@ def main(argv=None):
                          "DiLoCo): registers outer.round*.sync.* sites in "
                          "the plan lookup (needs --pods > 1)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.config:
         from repro.launch.config import load_run_config, merge_cli, resolve_model
@@ -99,8 +105,10 @@ def main(argv=None):
         # not provide — the acc.* sites still shape the plan lookup below.
         args.grad_accum = args.accumulate
     plan_active = False
+    plan_scope = contextlib.nullcontext()
     if args.tuned_plan:
-        apply_tuned_plan(args.tuned_plan, expect_arch=cfg.name)
+        plan_scope = load_tuned_plan(args.tuned_plan,
+                                     expect_arch=cfg.name).applied()
         plan_active = True
     elif args.plan_repo:
         plan_hw = args.plan_hardware
@@ -123,45 +131,40 @@ def main(argv=None):
                        warmup=max(5, args.steps // 10),
                        total_steps=args.steps, grad_accum=args.grad_accum)
 
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split("x"))
-        axes = ("data", "model")[:len(shape)]
-        from repro.launch.mesh import make_mesh
-        mesh = make_mesh(shape, axes)
-        jax.sharding.set_mesh(mesh)
-        if plan_active and "model" in axes:
-            # an installed plan reaches the emitted program through the
-            # plan-aware trunk: per-layer explicit collectives whose sites
-            # resolve against it (falls back inside the model on
-            # indivisible shapes)
-            from dataclasses import replace as dc_replace
-            tcfg = dc_replace(tcfg, sited_mesh=mesh)
-        rng = jax.random.PRNGKey(0)
-        with CT.use_axes(("data",), "model"):
-            params = M.init_params(cfg, rng)
-            p_spec = SH.param_specs(params, mesh)
+    with contextlib.ExitStack() as scope:
+        # the plan binds at trace time, which happens inside train_loop
+        scope.enter_context(plan_scope)
+        params = None
+        if args.mesh:
+            shape = tuple(int(x) for x in args.mesh.split("x"))
+            axes = ("data", "model")[:len(shape)]
             from jax.sharding import NamedSharding
+
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh(shape, axes)
+            scope.enter_context(jax.set_mesh(mesh))
+            scope.enter_context(CT.use_axes(("data",), "model"))
+            if plan_active and "model" in axes:
+                # an installed plan reaches the emitted program through the
+                # plan-aware trunk: per-layer explicit collectives whose
+                # sites resolve against it (falls back inside the model on
+                # indivisible shapes)
+                from dataclasses import replace as dc_replace
+                tcfg = dc_replace(tcfg, sited_mesh=mesh)
+            params = M.init_params(cfg, jax.random.PRNGKey(0))
+            p_spec = SH.param_specs(params, mesh)
             params = jax.device_put(
                 params, jax.tree.map(lambda s: NamedSharding(mesh, s), p_spec))
-            opt_state = adamw.init_state(params)
-            step_fn = jax.jit(make_train_step(cfg, tcfg))
-            for step in range(args.steps):
-                batch = {k: jnp.asarray(v) for k, v in next(data).items()}
-                params, opt_state, metrics = step_fn(params, opt_state, batch,
-                                                     jnp.asarray(step))
-                if step % args.log_every == 0:
-                    print(f"step {step:4d} loss {float(metrics['loss']):.4f}")
-        history = None
-    else:
         params, history = train_loop(cfg, tcfg, data, steps=args.steps,
-                                     log_every=args.log_every)
+                                     params=params, log_every=args.log_every)
 
     if args.ckpt:
         checkpoint.save(args.ckpt, params, step=args.steps)
         print(f"checkpoint written to {args.ckpt}")
-    if history:
+    if history["loss"]:
         print(f"final loss {history['loss'][-1]:.4f} "
               f"(first {history['loss'][0]:.4f})")
+    return params, history
 
 
 if __name__ == "__main__":

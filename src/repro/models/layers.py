@@ -534,8 +534,7 @@ def _moe_ffn_explicit(p: Params, buf: jnp.ndarray, mesh, *, axis: str,
     (``{site}.a2a_comb``).  Chunk counts resolve per-site against the
     active tuned plan, so two MoE layers can emit different a2a structure
     from one plan (the paper's per-site co-tuning made HLO-visible)."""
-    from repro.parallel.collectives import (_chunked_a2a_local, runtime_for,
-                                            shard_map)
+    from repro.parallel.collectives import _chunked_a2a_local, runtime_for
 
     nc_disp = runtime_for(f"{site}.a2a_disp", "a2a").num_chunks
     nc_comb = runtime_for(f"{site}.a2a_comb", "a2a").num_chunks
@@ -551,10 +550,10 @@ def _moe_ffn_explicit(p: Params, buf: jnp.ndarray, mesh, *, axis: str,
         return _chunked_a2a_local(y, axis=axis, split_axis=1, concat_axis=0,
                                   num_chunks=nc_comb, site=f"{site}.a2a_comb")
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, axis, None), P(axis, None, None),
-                             P(axis, None, None), P(axis, None, None)),
-                   out_specs=P(None, axis, None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, axis, None), P(axis, None, None),
+                                 P(axis, None, None), P(axis, None, None)),
+                       out_specs=P(None, axis, None))
     return fn(buf, p["gate"], p["up"], p["down"])
 
 

@@ -146,6 +146,13 @@ def _unembed(cfg, p, x):
     return L.linear(p["head"], x)
 
 
+def logits(cfg, p: Params, batch, *, backend: Optional[str] = None):
+    """(B,S,vocab) logits of the uncached forward pass — the reference that
+    cached decoding and the loss are checked against."""
+    x, _, _ = forward_hidden(cfg, p, batch, backend=backend)
+    return _unembed(cfg, p, x)
+
+
 # ---------------------------------------------------------------------------
 # training loss (chunked cross-entropy: the full (B,S,V) logits tensor is
 # never materialized — each chunk's logits are recomputed in the backward

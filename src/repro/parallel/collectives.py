@@ -51,21 +51,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:                   # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
-
-
-def axis_size(axis: str) -> int:
-    """Concrete mesh-axis size inside a shard_map body (``lax.axis_size`` on
-    new jax; on older jax ``psum(1, axis)`` folds to a static int)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
 
 
 @dataclass(frozen=True)
@@ -305,7 +292,7 @@ def _ring_ag_matmul_local(x, w, *, axis: str, num_chunks: int, site: str = "ag")
     """Per-device body: hold one sequence shard, rotate shards around the
     ring; each step multiplies the currently-held shard so communication of
     the next shard overlaps with this step's matmul."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     Tl = x.shape[-2]
     out_shape = x.shape[:-2] + (n * Tl, w.shape[-1])
@@ -330,12 +317,9 @@ def _ring_ag_matmul_local(x, w, *, axis: str, num_chunks: int, site: str = "ag")
         x_cur = lax.ppermute(x_cur, axis, perm)
         return (x_cur, out)
 
-    out = jnp.zeros(out_shape, x.dtype)
-    try:  # newer jax: align varying-manual-axes type with the inputs
-        vma = tuple(set(jax.typeof(x).vma) | set(jax.typeof(w).vma))
-        out = lax.pvary(out, vma)
-    except AttributeError:
-        pass
+    # the accumulator varies over the same manual axes as the inputs
+    vma = tuple(set(jax.typeof(x).vma) | set(jax.typeof(w).vma))
+    out = lax.pcast(jnp.zeros(out_shape, x.dtype), vma, to="varying")
     _, out = lax.fori_loop(0, n, body, (x, out))
     return out
 
@@ -362,7 +346,7 @@ def mm_rs_ref(x, w):
 
 
 def _mm_rs_local(x, w, *, axis: str, num_chunks: int, site: str = "rs"):
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     T = x.shape[-2]
     if num_chunks <= 1 or T % (num_chunks * n):
         if num_chunks > 1:

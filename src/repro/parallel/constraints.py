@@ -45,10 +45,11 @@ def _dp(a):
 
 
 def _constrain(x, spec: P):
-    try:
-        return lax.with_sharding_constraint(x, spec)
-    except Exception:      # no ambient mesh (eager smoke test) — no-op
+    """Pin ``x`` to ``spec`` on the ambient mesh; a no-op only when no mesh
+    is set (``jax.set_mesh``).  A spec the mesh rejects raises."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return lax.with_sharding_constraint(x, spec)
 
 
 def btd(x):

@@ -14,11 +14,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.collectives import (_warn_unchunked, axis_size,
-                                        runtime_for, shard_map)
+from repro.parallel.collectives import _warn_unchunked, runtime_for
 
 
 def _chunked_ppermute(x, axis: str, perm, *, num_chunks: int, site: str):
@@ -41,7 +40,7 @@ def _pipeline_local(params, x_mb, *, fn: Callable, axis: str, microbatches: int,
     squeezed by shard_map).  x_mb: (M, mb, ...) microbatched input
     (replicated).  Returns (M, mb, ...) outputs (only the last stage's
     contribution is non-zero; caller psums over the stage axis)."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     M = microbatches
     params = jax.tree.map(lambda a: a[0], params)       # drop stage dim
@@ -70,11 +69,9 @@ def _pipeline_local(params, x_mb, *, fn: Callable, axis: str, microbatches: int,
     buf0 = jnp.zeros(mb_shape, x_mb.dtype)
     out_shape = jax.eval_shape(fn, params, jax.ShapeDtypeStruct(mb_shape, x_mb.dtype))
     ys0 = jnp.zeros((M,) + out_shape.shape, out_shape.dtype)
-    try:   # buffers become stage-varying inside the loop (params vary)
-        buf0 = lax.pvary(buf0, (axis,))
-        ys0 = lax.pvary(ys0, (axis,))
-    except AttributeError:
-        pass
+    # buffers become stage-varying inside the loop (params vary)
+    buf0 = lax.pcast(buf0, (axis,), to="varying")
+    ys0 = lax.pcast(ys0, (axis,), to="varying")
     _, ys = lax.fori_loop(0, n + M - 1, tick, (buf0, ys0))
     # only the last stage's ys are real; zero elsewhere then psum outside
     ys = jnp.where(stage == n - 1, ys, jnp.zeros_like(ys))

@@ -152,16 +152,19 @@ class ContinuousEngine:
             admits.append((slot, req))
         if not admits:
             return
-        plen = max(len(r.prompt) for _, r in admits)
+        # the prompt's last token is decoded, not prefilled: its decode
+        # step yields the first new token
+        plen = max(len(r.prompt) for _, r in admits) - 1
         toks = np.zeros((len(admits), plen), np.int32)
         lens = np.zeros((len(admits),), np.int32)
         for i, (_, r) in enumerate(admits):
-            toks[i, :len(r.prompt)] = r.prompt
-            lens[i] = len(r.prompt)
-        fresh = jax.vmap(lambda _: M.init_caches(self.cfg, 1, self.max_seq))(
+            toks[i, :len(r.prompt) - 1] = r.prompt[:-1]
+            lens[i] = len(r.prompt) - 1
+        filled = jax.vmap(lambda _: M.init_caches(self.cfg, 1, self.max_seq))(
             jnp.arange(len(admits)))
-        filled = prefill(self.params, jnp.asarray(toks),
-                         jnp.asarray(lens), fresh)
+        if plen:
+            filled = prefill(self.params, jnp.asarray(toks),
+                             jnp.asarray(lens), filled)
         # scatter the admitted slots' caches / current tokens into place
         slot_ids = jnp.asarray([s for s, _ in admits])
         self.caches = jax.tree.map(

@@ -163,10 +163,13 @@ class Engine:
             if self.cfg.family == "audio":
                 assert frames is not None
                 caches["memory"] = jnp.asarray(frames)
-            batch = {"tokens": jnp.asarray(toks)}
-            caches = self._prefill_ragged(prefill, batch, caches, lens)
+            # the prompt's last token is decoded, not prefilled: its decode
+            # step yields the first new token
+            if plen > 1:
+                batch = {"tokens": jnp.asarray(toks[:, :plen - 1])}
+                caches = self._prefill_ragged(prefill, batch, caches, lens - 1)
             # decode each row from its true last token; the shared position
-            # counter sits at plen, so subtract each row's pad gap.
+            # counter sits at plen - 1, so subtract each row's pad gap.
             cur = jnp.asarray(toks[np.arange(self.batch), lens - 1][:, None])
             offs = jnp.asarray(plen - lens, jnp.int32)
             outs: List[List[int]] = [[] for _ in range(self.batch)]

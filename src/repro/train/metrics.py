@@ -1,14 +1,26 @@
 """Training metrics: analytic step FLOPs and MFU accounting.
 
 MFU = model FLOPs (6·N_active·tokens, no remat credit) / wall / peak —
-the MaxText/PaLM convention; hardware peaks default to TPU v5e.
+the MaxText/PaLM convention.  The peak comes from ``PEAK_BF16_FLOPS``,
+keyed by the device's ``device_kind``; a device that is not in the table
+has no MFU (``None``: not measured), never a borrowed peak.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Optional
 
-TPU_V5E_PEAK = 197e12
+# Dense bf16 peak per chip, keyed by ``jax.Device.device_kind``.
+# TPU v5e: 197 TFLOP/s (Google Cloud documentation, "TPU v5e").
+PEAK_BF16_FLOPS: Dict[str, float] = {
+    "TPU v5 lite": 197e12,
+}
+
+
+def peak_flops(device_kind: str) -> Optional[float]:
+    """The table's peak for ``device_kind``, or ``None`` when the device is
+    not in it (then MFU is not measured)."""
+    return PEAK_BF16_FLOPS.get(device_kind)
 
 
 @dataclass
@@ -23,17 +35,19 @@ def train_step_flops(cfg, tokens: int, *, remat: bool = True) -> StepFlops:
                      executed=(8.0 if remat else 6.0) * n * tokens)
 
 
-def mfu(cfg, tokens: int, step_seconds: float, *, chips: int = 1,
-        peak: float = TPU_V5E_PEAK) -> float:
+def mfu(cfg, tokens: int, step_seconds: float, *, peak: float,
+        chips: int = 1) -> float:
     f = train_step_flops(cfg, tokens)
     return f.model / max(step_seconds, 1e-12) / (chips * peak)
 
 
 class Tracker:
-    """Rolling window over step metrics; used by the train loop."""
+    """Rolling window over step metrics; used by the train loop.  ``peak``
+    is the per-chip peak (``peak_flops``); ``None`` reports MFU as
+    ``None``."""
 
     def __init__(self, cfg, tokens_per_step: int, *, chips: int = 1,
-                 peak: float = TPU_V5E_PEAK, window: int = 20):
+                 peak: Optional[float] = None, window: int = 20):
         self.cfg = cfg
         self.tokens = tokens_per_step
         self.chips = chips
@@ -41,13 +55,14 @@ class Tracker:
         self.window = window
         self.times: list = []
 
-    def update(self, step_seconds: float) -> Dict[str, float]:
+    def update(self, step_seconds: float) -> Dict[str, Optional[float]]:
         self.times.append(step_seconds)
         recent = self.times[-self.window:]
         avg = sum(recent) / len(recent)
         return {
             "step_s": step_seconds,
             "tokens_per_s": self.tokens / avg,
-            "mfu": mfu(self.cfg, self.tokens, avg, chips=self.chips,
-                       peak=self.peak),
+            "mfu": (None if self.peak is None else
+                    mfu(self.cfg, self.tokens, avg, chips=self.chips,
+                        peak=self.peak)),
         }

@@ -81,7 +81,7 @@ def make_train_step(cfg, tcfg: TrainConfig):
                 gsum = g if gsum is None else jax.tree.map(jnp.add, gsum, g)
                 tot_loss = tot_loss + l
                 metrics = m
-            scale = n * collectives.axis_size(tcfg.accum_axis)
+            scale = n * jax.lax.axis_size(tcfg.accum_axis)
             grads = jax.tree.map(lambda a: a / scale, gsum)
             loss = tot_loss / n
         elif tcfg.grad_accum > 1:
@@ -131,32 +131,60 @@ def make_train_step(cfg, tcfg: TrainConfig):
     return train_step
 
 
+def _shardings(tree):
+    return jax.tree.map(lambda a: a.sharding, tree)
+
+
+def _committed(tree):
+    return jax.device_put(tree, _shardings(tree))
+
+
+def jit_train_step(cfg, tcfg: TrainConfig, params, opt_state):
+    """The step ``train_loop`` runs, for state laid out as ``params`` and
+    ``opt_state`` (arrays or ``ShapeDtypeStruct``s with shardings).  The
+    state is donated, and leaves the step laid out as it entered, so step
+    1 reuses step 0's executable."""
+    return jax.jit(make_train_step(cfg, tcfg), donate_argnums=(0, 1),
+                   out_shardings=(_shardings(params), _shardings(opt_state),
+                                  None))
+
+
 def train_loop(cfg, tcfg: TrainConfig, data_iter, *, steps: int,
                rng=None, params=None, log_every: int = 10,
                callback=None) -> Tuple[Any, Dict[str, list]]:
-    """Single-host training driver (examples / smoke tests)."""
+    """Training loop: the launcher's (``params`` may arrive sharded
+    over a mesh) and the examples'.
+
+    ``history["step_time"][i]`` is the host wall time of step ``i`` from
+    its dispatch to ``block_until_ready`` on its outputs (batch
+    preparation excluded); step 0 includes tracing and compilation.
+    ``history["mfu"]`` is ``None`` on a device with no peak in
+    ``metrics.PEAK_BF16_FLOPS``.  ``params`` is consumed (donated)."""
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     if params is None:
         params = M.init_params(cfg, rng)
-    opt_state = adamw.init_state(params)
-    step_fn = jax.jit(make_train_step(cfg, tcfg))
+    params = _committed(params)
+    opt_state = _committed(adamw.init_state(params))
+    step_fn = jit_train_step(cfg, tcfg, params, opt_state)
     history: Dict[str, list] = {"loss": [], "step_time": [], "mfu": []}
+    chips = len(jax.tree.leaves(params)[0].sharding.device_set)
     tracker = None
-    t_prev = time.perf_counter()
     for step in range(steps):
         batch = {k: jnp.asarray(v) for k, v in next(data_iter).items()}
-        params, opt_state, metrics = step_fn(params, opt_state, batch,
-                                             jnp.asarray(step))
+        t0 = time.perf_counter()
+        params, opt_state, metrics = jax.block_until_ready(
+            step_fn(params, opt_state, batch, jnp.asarray(step)))
+        dt = time.perf_counter() - t0
         loss = float(metrics["loss"])
-        t_now = time.perf_counter()
         if tracker is None:
             tokens = int(batch["tokens"].shape[0] * batch["tokens"].shape[1])
-            tracker = MET.Tracker(cfg, tokens)
-        m = tracker.update(t_now - t_prev)
+            tracker = MET.Tracker(
+                cfg, tokens, chips=chips,
+                peak=MET.peak_flops(jax.devices()[0].device_kind))
+        m = tracker.update(dt)
         history["loss"].append(loss)
-        history["step_time"].append(t_now - t_prev)
+        history["step_time"].append(dt)
         history["mfu"].append(m["mfu"])
-        t_prev = t_now
         if callback:
             callback(step, metrics)
         if log_every and step % log_every == 0:

@@ -63,7 +63,7 @@ def _mutant(plan):
 def test_jaxpr_walker_finds_collective_chunk_loop():
     mesh = make_mesh((jax.device_count(),), ("dp",))
     grads = {"w": jnp.ones((8, 4))}
-    fn = C.shard_map(
+    fn = jax.shard_map(
         lambda t: C.psum_tree_chunked(t, "dp", num_chunks=4),
         mesh=mesh, in_specs=({"w": P("dp")},), out_specs={"w": P("dp")})
     g = graph_from_jaxpr(jax.make_jaxpr(fn)(grads))
@@ -254,7 +254,7 @@ def test_trace_and_verify_roundtrip_and_no_install_control():
     grads = {"w": jnp.ones((8, 4))}
 
     def fn(t):
-        return C.shard_map(
+        return jax.shard_map(
             lambda g: C.psum_tree_chunked(g, "dp", site="acc.step0.rs_grads"),
             mesh=mesh, in_specs=({"w": P("dp")},),
             out_specs={"w": P("dp")})(t)
@@ -498,7 +498,7 @@ def test_engines_plumb_plan_lint():
 def test_degraded_warning_structured_and_deduped():
     mesh = make_mesh((jax.device_count(),), ("dp",))
     grads = {"w": jnp.ones((5, 2))}   # 5 % 2 != 0
-    fn = C.shard_map(
+    fn = jax.shard_map(
         lambda t: C.psum_tree_chunked(t, "dp", num_chunks=2,
                                       site="acc.step0.rs_grads"),
         mesh=mesh, in_specs=({"w": P("dp")},), out_specs={"w": P("dp")})

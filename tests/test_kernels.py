@@ -1,5 +1,7 @@
-"""Per-kernel correctness: Pallas (interpret mode) and chunked-matmul forms
-vs the naive per-step jnp oracle, swept over shapes and dtypes."""
+"""Per-kernel correctness: Pallas (interpret mode, on the CPU) and
+chunked-matmul forms vs the naive per-step jnp oracle, swept over shapes
+and dtypes.  Every Pallas call here names ``interpret=True``: the tests run
+on a CPU backend, and the chip compile is pinned by test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,7 +25,7 @@ def _wkv_inputs(B, S, H, K, dtype, seed=0):
 def test_wkv6_pallas_matches_ref(B, S, H, K, dtype):
     r, k, v, w_log, u = _wkv_inputs(B, S, H, K, dtype)
     y_ref, s_ref = ref.wkv6_ref(r, k, v, w_log, u)
-    y, s = wkv6_pallas(r, k, v, w_log, u, chunk=32)
+    y, s = wkv6_pallas(r, k, v, w_log, u, chunk=32, interpret=True)
     scale_y = float(jnp.abs(y_ref.astype(jnp.float32)).max()) or 1.0
     rtol = 3e-2 if dtype == jnp.bfloat16 else 1e-3
     assert jnp.abs(y.astype(jnp.float32) - y_ref.astype(jnp.float32)).max() < rtol * scale_y
@@ -57,7 +59,7 @@ def _ssd_inputs(B, S, H, P, N, dtype, seed=0):
 def test_ssd_pallas_matches_ref(B, S, H, P, N, dtype):
     x, dt, A, Bm, Cm, D = _ssd_inputs(B, S, H, P, N, dtype)
     y_ref, s_ref = ref.ssd_ref(x, dt, A, Bm, Cm, D)
-    y, s = ssd_pallas(x, dt, A, Bm, Cm, D, chunk=32)
+    y, s = ssd_pallas(x, dt, A, Bm, Cm, D, chunk=32, interpret=True)
     scale_y = float(jnp.abs(y_ref.astype(jnp.float32)).max()) or 1.0
     rtol = 3e-2 if dtype == jnp.bfloat16 else 1e-3
     assert jnp.abs(y.astype(jnp.float32) - y_ref.astype(jnp.float32)).max() < rtol * scale_y
@@ -79,22 +81,32 @@ def test_ssd_state_continuation():
 def test_rmsnorm_pallas(shape, dtype):
     x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
     scale = jnp.linspace(0.5, 1.5, shape[-1])
-    y = rmsnorm_pallas(x, scale)
+    y = rmsnorm_pallas(x, scale, interpret=True)
     y_ref = ref.rmsnorm_ref(x, scale)
     assert jnp.abs(y.astype(jnp.float32) - y_ref.astype(jnp.float32)).max() < 2e-2
 
 
 def test_ops_dispatch_backends():
     r, k, v, w_log, u = _wkv_inputs(1, 64, 2, 16, jnp.float32)
-    outs = [ops.wkv6(r, k, v, w_log, u, backend=b)[0]
+    outs = [ops.wkv6(r, k, v, w_log, u, backend=b, interpret=True)[0]
             for b in ("ref", "chunked", "pallas")]
     for o in outs[1:]:
         assert jnp.abs(o - outs[0]).max() < 1e-3
     x, dt, A, Bm, Cm, D = _ssd_inputs(1, 64, 2, 8, 16, jnp.float32)
-    outs = [ops.ssd(x, dt, A, Bm, Cm, D, backend=b)[0]
+    outs = [ops.ssd(x, dt, A, Bm, Cm, D, backend=b, interpret=True)[0]
             for b in ("ref", "chunked", "pallas")]
     for o in outs[1:]:
         assert jnp.abs(o - outs[0]).max() < 1e-3
+
+
+def test_pallas_interpret_is_decided_from_the_backend(monkeypatch):
+    # the tests run on a CPU backend: the interpreter is the default there
+    assert ops.pallas_interpret() is True
+    assert ops.pallas_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.pallas_interpret() is False
+    with pytest.raises(ValueError, match="TPU"):
+        ops.pallas_interpret(True)
 
 
 def test_ops_pad_non_multiple_seq():
@@ -116,7 +128,8 @@ def test_flash_attention_pallas(B, S, Hq, Hkv, h, causal):
     q = jax.random.normal(ks[0], (B, S, Hq, h))
     k = jax.random.normal(ks[1], (B, S, Hkv, h))
     v = jax.random.normal(ks[2], (B, S, Hkv, h))
-    o = flash_attention(q, k, v, causal=causal, q_block=32, kv_block=32)
+    o = flash_attention(q, k, v, causal=causal, q_block=32, kv_block=32,
+                        interpret=True)
     G = Hq // Hkv
     kk, vv = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / math.sqrt(h)

@@ -16,8 +16,21 @@ def test_train_step_flops_and_mfu():
     assert f.model == pytest.approx(6 * cfg.param_count(active_only=True) * tokens)
     assert f.executed > f.model
     # perfect-efficiency sanity: executing model flops at peak -> MFU ~0.75
-    ideal_t = f.executed / (256 * MET.TPU_V5E_PEAK)
-    assert 0.70 < MET.mfu(cfg, tokens, ideal_t, chips=256) < 0.78
+    peak = MET.peak_flops("TPU v5 lite")
+    ideal_t = f.executed / (256 * peak)
+    assert 0.70 < MET.mfu(cfg, tokens, ideal_t, chips=256, peak=peak) < 0.78
+
+
+def test_peak_table_is_keyed_by_device_kind():
+    assert MET.peak_flops("TPU v5 lite") == 197e12
+    # a device outside the table has no peak, so its MFU is not measured
+    assert MET.peak_flops("cpu") is None
+    tr = MET.Tracker(get_config("phi2-2b"), tokens_per_step=1024,
+                     peak=MET.peak_flops("cpu"))
+    assert tr.update(1.0)["mfu"] is None
+    tr = MET.Tracker(get_config("phi2-2b"), tokens_per_step=1024,
+                     peak=MET.peak_flops("TPU v5 lite"))
+    assert tr.update(1.0)["mfu"] > 0
 
 
 def test_tracker_window():

@@ -119,3 +119,63 @@ def test_engine_generate_and_probe():
     # greedy decode is deterministic
     outs2 = eng.generate(prompts, max_new=4)
     assert outs == outs2
+
+
+def test_constrain_skips_only_without_a_mesh():
+    import pytest
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    from repro.parallel import constraints as CT
+
+    x = jnp.ones((2, 4, 8))
+    assert CT._constrain(x, P("nope")) is x          # no mesh set: no-op
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
+        with pytest.raises(ValueError, match="nope"):
+            CT._constrain(x, P("nope"))              # a bad spec surfaces
+        with CT.use_axes(("data",), "model"):
+            assert jax.jit(CT.btd)(x).shape == x.shape
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    import os
+
+    from repro.launch import config
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", was)
+        assert config.configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was   # left to JAX
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = config.configure_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_train_main_returns_what_it_ran_and_scopes_plan_and_mesh(tmp_path):
+    from repro.core import extract_workload, tune
+    from repro.core.extract import parse_parallel
+    from repro.launch import train
+    from repro.parallel import collectives as C
+
+    cfg = get_smoke_config("h2o-danube-1.8b")
+    plan = tune(extract_workload(cfg, parse_parallel("tp:4"), seq=32,
+                                 global_batch=2), "tpu-v5e", seed=0)
+    plan.save(str(tmp_path / "plan.json"))
+    base = ["--arch", "h2o-danube-1.8b", "--smoke", "--steps", "2",
+            "--seq", "32", "--batch", "2", "--log-every", "0"]
+    p0, h0 = train.main(base)
+    p1, h1 = train.main(base + ["--mesh", "1x1", "--tuned-plan",
+                                str(tmp_path / "plan.json")])
+    assert len(h0["loss"]) == len(h1["loss"]) == len(h1["step_time"]) == 2
+    np.testing.assert_allclose(h1["loss"], h0["loss"], atol=1e-4)
+    assert h0["mfu"] == [None, None]       # the CPU has no peak in the table
+    assert jax.tree.structure(p0) == jax.tree.structure(p1)
+    # neither the plan nor the mesh outlives the call
+    assert C.active_runtime_plan() == {}
+    assert jax.sharding.get_abstract_mesh().empty
