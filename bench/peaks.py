@@ -1,0 +1,19 @@
+"""Published peaks of one chip, keyed by ``jax.Device.device_kind``.
+
+TPU v5e ("TPU v5 lite"): 197 TFLOP/s in bf16 and 819 GB/s of HBM
+bandwidth a chip (Google Cloud documentation, "TPU v5e").  A device that
+is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def peak(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peak for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
