@@ -1,0 +1,8 @@
+"""Device milliseconds a step in the operations under the ``attention``
+scope, collectives left out, averaged over the cell's chips
+(``bench/scopes.py``)."""
+from bench import scopes as S
+
+
+def read(ctx):
+    return S.part_ms(S.read(ctx), ("attention",), collectives=False)

@@ -97,20 +97,24 @@ def layer_fwd(p: Params, cfg, x: jnp.ndarray, positions, cache: Optional[Params]
     prefix (``tp.layer{i}.mlp`` / ``ep.layer{j}.moe``, or
     ``serve.layer{i}.*`` when ``serve`` marks the decode-shape layout)."""
     def ff(q, v):
-        if mesh is not None and not use_moe:
-            if serve:
-                return serve_mlp(q, v, cfg.mlp_kind, mesh, axis=axis,
-                                 site=site or "serve.mlp")
-            return tp_mlp(q, v, cfg.mlp_kind, mesh, axis=axis,
-                          site=site or "tp.mlp")
-        return L.mlp(q, v, cfg.mlp_kind)
+        with jax.named_scope("mlp"):
+            if mesh is not None and not use_moe:
+                if serve:
+                    return serve_mlp(q, v, cfg.mlp_kind, mesh, axis=axis,
+                                     site=site or "serve.mlp")
+                return tp_mlp(q, v, cfg.mlp_kind, mesh, axis=axis,
+                              site=site or "tp.mlp")
+            return L.mlp(q, v, cfg.mlp_kind)
 
     x = CT.btd(x)
     h = L.norm(p["ln1"], x, cfg.norm_kind)
-    if cfg.attn_kind == "mla":
-        attn_out, new_cache = L.mla_attention(p["attn"], cfg, h, positions, cache=cache)
-    else:
-        attn_out, new_cache = L.attention(p["attn"], cfg, h, positions, cache=cache)
+    with jax.named_scope("attention"):
+        if cfg.attn_kind == "mla":
+            attn_out, new_cache = L.mla_attention(p["attn"], cfg, h, positions,
+                                                  cache=cache)
+        else:
+            attn_out, new_cache = L.attention(p["attn"], cfg, h, positions,
+                                              cache=cache)
 
     aux = jnp.zeros((), jnp.float32)
     if cfg.parallel_block:           # phi-2 style: mlp reads the same norm
@@ -119,8 +123,9 @@ def layer_fwd(p: Params, cfg, x: jnp.ndarray, positions, cache: Optional[Params]
         x = x + attn_out
         h2 = L.norm(p["ln2"], x, cfg.norm_kind)
         if use_moe:
-            ff_out, aux = L.moe_block(p["moe"], cfg, h2, mesh=mesh, axis=axis,
-                                      site=site or "ep.moe")
+            with jax.named_scope("moe"):
+                ff_out, aux = L.moe_block(p["moe"], cfg, h2, mesh=mesh,
+                                          axis=axis, site=site or "ep.moe")
         else:
             ff_out = ff(p["mlp"], h2)
         x = x + ff_out
@@ -225,9 +230,15 @@ def _trunk_fwd_sited(p: Params, cfg, x, positions, mesh, *, axis: str,
         stacked = p[seg]
         n_seg = jax.tree.leaves(stacked)[0].shape[0]
         seg_cache = caches[seg] if caches is not None else None
+        # every layer's slice is taken in one scope, so a trace reads the
+        # slices, and any sum of the layers' gradients back into the
+        # stacked buffers that XLA keeps as an operation of its own, under
+        # ``layer_params``
+        with jax.named_scope("layer_params"):
+            lps = [jax.tree.map(lambda a: a[j], stacked) for j in range(n_seg)]
         layer_caches = []
         for j in range(n_seg):
-            lp = jax.tree.map(lambda a: a[j], stacked)
+            lp = lps[j]
             if caches is None:
                 site = f"ep.layer{j}.moe" if use_moe else f"tp.layer{li}.mlp"
                 lc = None

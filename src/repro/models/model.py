@@ -92,10 +92,12 @@ def _positions(cfg, batch, B: int, S: int, t0) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def _embed_inputs(cfg, p, batch) -> jnp.ndarray:
-    x = L.embed(p["embed"], batch["tokens"])
-    if cfg.family == "vlm" and batch.get("patches") is not None:
-        n = batch["patches"].shape[1]
-        x = jnp.concatenate([batch["patches"].astype(x.dtype), x[:, n:]], axis=1)
+    with jax.named_scope("embed"):
+        x = L.embed(p["embed"], batch["tokens"])
+        if cfg.family == "vlm" and batch.get("patches") is not None:
+            n = batch["patches"].shape[1]
+            x = jnp.concatenate([batch["patches"].astype(x.dtype), x[:, n:]],
+                                axis=1)
     return x
 
 
@@ -160,6 +162,11 @@ def logits(cfg, p: Params, batch, *, backend: Optional[str] = None):
 # ---------------------------------------------------------------------------
 
 def chunked_ce(cfg, p, x, targets, mask, *, chunk: int = 256):
+    with jax.named_scope("loss"):
+        return _chunked_ce(cfg, p, x, targets, mask, chunk)
+
+
+def _chunked_ce(cfg, p, x, targets, mask, chunk):
     B, S, D = x.shape
     pad = (-S) % chunk
     if pad:

@@ -218,6 +218,15 @@ def runtime_for(site: str, cls: Optional[str] = None) -> CollectiveRuntime:
     return explain_runtime(site, cls)[0]
 
 
+def site_scope(site: str):
+    """The ``jax.named_scope`` a collective site's helper runs under:
+    ``site:<SiteId>``.  It lands in the compiled HLO's ``op_name`` metadata
+    of every collective and chunked matmul the helper emits (wrapped in
+    ``transpose(...)`` in the backward pass), so a device trace can be read
+    per site; it changes nothing that runs."""
+    return jax.named_scope(f"site:{site}")
+
+
 def _resolve_chunks(num_chunks, site: str, cls: Optional[str] = None) -> int:
     """Explicit ``num_chunks`` wins; ``None`` defers to the active plan."""
     return runtime_for(site, cls).num_chunks if num_chunks is None else num_chunks
@@ -332,7 +341,8 @@ def ring_ag_matmul(x, w, mesh: Mesh, *, axis: str = "model",
     fn = shard_map(partial(_ring_ag_matmul_local, axis=axis,
                            num_chunks=num_chunks, site=site),
                    mesh=mesh, in_specs=(x_spec, w_spec), out_specs=out_spec)
-    return fn(x, w)
+    with site_scope(site):
+        return fn(x, w)
 
 
 # ---------------------------------------------------------------------------
@@ -379,7 +389,8 @@ def mm_reduce_scatter(x, w, mesh: Mesh, *, axis: str = "model",
     fn = shard_map(partial(_mm_rs_local, axis=axis, num_chunks=num_chunks,
                            site=site),
                    mesh=mesh, in_specs=(x_spec, w_spec), out_specs=out_spec)
-    return fn(x, w)
+    with site_scope(site):
+        return fn(x, w)
 
 
 # ---------------------------------------------------------------------------
@@ -392,15 +403,17 @@ def _chunked_a2a_local(xl, *, axis: str, split_axis: int, concat_axis: int,
     """Local body: one all_to_all, or ``num_chunks`` sequential a2a's over
     the trailing feature dim (reused by ``chunked_all_to_all`` and the
     explicit expert-parallel MoE FFN)."""
-    if num_chunks <= 1 or xl.shape[-1] % num_chunks:
-        if num_chunks > 1:
-            _warn_unchunked(site, num_chunks,
-                            f"the trailing feature dim ({xl.shape[-1]})")
-        return lax.all_to_all(xl, axis, split_axis, concat_axis, tiled=True)
-    blocks = jnp.stack(jnp.split(xl, num_chunks, axis=-1))
-    ys = lax.map(lambda b: lax.all_to_all(b, axis, split_axis, concat_axis,
-                                          tiled=True), blocks)
-    return jnp.concatenate(list(ys), axis=-1)
+    with site_scope(site):
+        if num_chunks <= 1 or xl.shape[-1] % num_chunks:
+            if num_chunks > 1:
+                _warn_unchunked(site, num_chunks,
+                                f"the trailing feature dim ({xl.shape[-1]})")
+            return lax.all_to_all(xl, axis, split_axis, concat_axis,
+                                  tiled=True)
+        blocks = jnp.stack(jnp.split(xl, num_chunks, axis=-1))
+        ys = lax.map(lambda b: lax.all_to_all(b, axis, split_axis,
+                                              concat_axis, tiled=True), blocks)
+        return jnp.concatenate(list(ys), axis=-1)
 
 
 def chunked_all_to_all(x, mesh: Mesh, *, axis: str = "model",
@@ -452,4 +465,5 @@ def psum_tree_chunked(tree, axis: str, *, num_chunks: int | None = None,
         ys = lax.map(lambda b: lax.psum(b, axis), blocks)
         return jnp.concatenate(list(ys), axis=0)
 
-    return jax.tree.map(one, tree)
+    with site_scope(site):
+        return jax.tree.map(one, tree)

@@ -42,27 +42,15 @@ def mfu(cfg, tokens: int, step_seconds: float, *, peak: float,
 
 
 class Tracker:
-    """Rolling window over step metrics; used by the train loop.  ``peak``
-    is the per-chip peak (``peak_flops``); ``None`` reports MFU as
-    ``None``."""
+    """Rolling window over step times; used by the train loop."""
 
-    def __init__(self, cfg, tokens_per_step: int, *, chips: int = 1,
-                 peak: Optional[float] = None, window: int = 20):
-        self.cfg = cfg
+    def __init__(self, tokens_per_step: int, *, window: int = 20):
         self.tokens = tokens_per_step
-        self.chips = chips
-        self.peak = peak
         self.window = window
         self.times: list = []
 
-    def update(self, step_seconds: float) -> Dict[str, Optional[float]]:
+    def update(self, step_seconds: float) -> Dict[str, float]:
         self.times.append(step_seconds)
         recent = self.times[-self.window:]
         avg = sum(recent) / len(recent)
-        return {
-            "step_s": step_seconds,
-            "tokens_per_s": self.tokens / avg,
-            "mfu": (None if self.peak is None else
-                    mfu(self.cfg, self.tokens, avg, chips=self.chips,
-                        peak=self.peak)),
-        }
+        return {"step_s": step_seconds, "tokens_per_s": self.tokens / avg}

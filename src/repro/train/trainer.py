@@ -122,9 +122,10 @@ def make_train_step(cfg, tcfg: TrainConfig):
         else:
             (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch)
-        lr_scale = sched(step, warmup=tcfg.warmup, total=tcfg.total_steps)
-        params, opt_state, opt_metrics = adamw.apply_updates(
-            params, grads, opt_state, tcfg.opt, lr_scale)
+        with jax.named_scope("optimizer"):
+            lr_scale = sched(step, warmup=tcfg.warmup, total=tcfg.total_steps)
+            params, opt_state, opt_metrics = adamw.apply_updates(
+                params, grads, opt_state, tcfg.opt, lr_scale)
         metrics = dict(metrics, **opt_metrics, loss=loss)
         return params, opt_state, metrics
 
@@ -158,16 +159,14 @@ def train_loop(cfg, tcfg: TrainConfig, data_iter, *, steps: int,
     ``history["step_time"][i]`` is the host wall time of step ``i`` from
     its dispatch to ``block_until_ready`` on its outputs (batch
     preparation excluded); step 0 includes tracing and compilation.
-    ``history["mfu"]`` is ``None`` on a device with no peak in
-    ``metrics.PEAK_BF16_FLOPS``.  ``params`` is consumed (donated)."""
+    ``params`` is consumed (donated)."""
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     if params is None:
         params = M.init_params(cfg, rng)
     params = _committed(params)
     opt_state = _committed(adamw.init_state(params))
     step_fn = jit_train_step(cfg, tcfg, params, opt_state)
-    history: Dict[str, list] = {"loss": [], "step_time": [], "mfu": []}
-    chips = len(jax.tree.leaves(params)[0].sharding.device_set)
+    history: Dict[str, list] = {"loss": [], "step_time": []}
     tracker = None
     for step in range(steps):
         batch = {k: jnp.asarray(v) for k, v in next(data_iter).items()}
@@ -177,14 +176,11 @@ def train_loop(cfg, tcfg: TrainConfig, data_iter, *, steps: int,
         dt = time.perf_counter() - t0
         loss = float(metrics["loss"])
         if tracker is None:
-            tokens = int(batch["tokens"].shape[0] * batch["tokens"].shape[1])
             tracker = MET.Tracker(
-                cfg, tokens, chips=chips,
-                peak=MET.peak_flops(jax.devices()[0].device_kind))
+                int(batch["tokens"].shape[0] * batch["tokens"].shape[1]))
         m = tracker.update(dt)
         history["loss"].append(loss)
         history["step_time"].append(dt)
-        history["mfu"].append(m["mfu"])
         if callback:
             callback(step, metrics)
         if log_every and step % log_every == 0:

@@ -174,7 +174,7 @@ def test_train_main_returns_what_it_ran_and_scopes_plan_and_mesh(tmp_path):
                                 str(tmp_path / "plan.json")])
     assert len(h0["loss"]) == len(h1["loss"]) == len(h1["step_time"]) == 2
     np.testing.assert_allclose(h1["loss"], h0["loss"], atol=1e-4)
-    assert h0["mfu"] == [None, None]       # the CPU has no peak in the table
+    assert set(h0) == set(h1) == {"loss", "step_time"}
     assert jax.tree.structure(p0) == jax.tree.structure(p1)
     # neither the plan nor the mesh outlives the call
     assert C.active_runtime_plan() == {}
