@@ -186,11 +186,14 @@ def _kv_scan_attention(q, k, v, bias_fn, scale, kv_block: int, q0):
             "bngqs,bsnh->bngqh", p, vblk.astype(jnp.float32))
         return (m_new, l_new, acc_new), None
 
-    m0 = jnp.full((B, N, G, Qb), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, N, G, Qb), jnp.float32)
-    a0 = jnp.zeros((B, N, G, Qb, h), jnp.float32)
+    carry = (jnp.full((B, N, G, Qb), NEG_INF, jnp.float32),
+             jnp.zeros((B, N, G, Qb), jnp.float32),
+             jnp.zeros((B, N, G, Qb, h), jnp.float32))
+    vma = tuple(jax.typeof(qf).vma)
+    if vma:     # inside shard_map the carries vary over the axes q does
+        carry = tuple(lax.pcast(c, vma, to="varying") for c in carry)
     (m, l, acc), _ = lax.scan(
-        step, (m0, l0, a0),
+        step, carry,
         (jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0), jnp.arange(nblk)))
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     return jnp.moveaxis(out, -2, 1).astype(v.dtype)  # (B,Qb,N,G,h)
@@ -220,6 +223,109 @@ def _blockwise_attention(q, k, v, bias_fn, scale, kv_block: int,
     return out[:, :Sq]
 
 
+def _rope_qk(cfg, q, k, positions):
+    """RoPE / M-RoPE / partial RoPE on q (B,S,N,G,h) and k (B,S,N,h); the
+    identity for other position kinds.  Rotation is per head."""
+    if cfg.pos_kind not in ("rope", "mrope"):
+        return q, k
+    B, Sq, N, G, h = q.shape
+    qr, k = apply_rope(q.reshape(B, Sq, N * G, h), k, positions, head_dim=h,
+                       fraction=cfg.rope_fraction, theta=cfg.rope_theta,
+                       mrope_sections=cfg.mrope_sections if cfg.pos_kind == "mrope" else ())
+    return qr.reshape(B, Sq, N, G, h), k
+
+
+def _self_attend(q, k, v, positions, slopes, norms, *, cfg, causal: bool,
+                 rope: bool, kv_block: int, blockwise_threshold: int):
+    """Attention over whole sequences (train, cacheless prefill, cross):
+    q (B,Sq,N,G,h); k,v (B,Sk,N,h); ``slopes`` (N,G) ALiBi slopes of these
+    heads or None; ``norms`` the per-head q/k RMSNorm scales (may be
+    empty).  Every head is independent, so this runs unchanged on a
+    head shard inside ``shard_map``."""
+    B, Sq, N, G, h = q.shape
+    Sk = k.shape[1]
+    if norms:
+        q = norm(norms["q_norm"], q, "rmsnorm")
+        k = norm(norms["k_norm"], k, "rmsnorm")
+    if rope:
+        q, k = _rope_qk(cfg, q, k, positions)
+    scale = 1.0 / math.sqrt(h)
+    if not causal:
+        def bias_fn(q0, qlen, s0, slen):
+            return jnp.zeros((1, 1, 1, 1, slen), jnp.float32)
+    else:
+        q_pos_full = positions if positions.ndim == 2 else positions[0]
+        padq = (-Sq) % Q_BLOCK
+        if padq:        # bias_fn may be sliced from padded query blocks
+            q_pos_full = jnp.pad(q_pos_full, ((0, 0), (0, padq)))
+
+        def bias_fn(q0, qlen, s0, slen):
+            q_pos = lax.dynamic_slice_in_dim(q_pos_full, q0, qlen, axis=1)
+            kpos = s0 + jnp.arange(slen)
+            m = q_pos[:, :, None] >= kpos[None, None, :]
+            if cfg.sliding_window:
+                m &= q_pos[:, :, None] - kpos[None, None, :] < cfg.sliding_window
+            b = jnp.where(m, 0.0, NEG_INF)            # (B,qlen,slen)
+            b = b[:, None, None, :, :]
+            if slopes is not None:
+                dist = (kpos[None, None, :] - q_pos[:, :, None]).astype(jnp.float32)
+                b = b + slopes.reshape(1, N, G, 1, 1) * dist[:, None, None, :, :]
+            return b
+
+    if Sk > blockwise_threshold or Sq * Sk > blockwise_threshold ** 2:
+        return _blockwise_attention(q, k, v, bias_fn, scale, kv_block)
+    return _gqa_scores_to_out(q, k, v, bias_fn(0, Sq, 0, Sk), scale)
+
+
+def _head_parallel_axes(num_kv_heads: int, batch: int):
+    """``(dp, tp)``: the data axes (a tuple) and the model axis for running
+    self-attention head-parallel on the ambient mesh, or None where it
+    cannot: no axes installed (``constraints.use_axes``), no mesh, a model
+    axis of size 1, or KV heads the model axis does not divide (warned
+    once, under the label ``attention.heads``, which no tuned site has).
+    The batch goes over the data axes where it divides them, else ``dp``
+    is empty and the batch stays whole."""
+    a = CT.axes()
+    mesh = jax.sharding.get_abstract_mesh()
+    if a is None or mesh.empty or a["tp"] not in mesh.axis_names:
+        return None
+    tp = a["tp"]
+    n = mesh.shape[tp]
+    if n == 1:
+        return None
+    if num_kv_heads % n:
+        from repro.parallel.collectives import warn_degraded
+
+        warn_degraded("attention.heads",
+                      f"{num_kv_heads} KV heads are not divisible by the "
+                      f"{tp!r} axis ({n}); self-attention keeps GSPMD's "
+                      "layout", stacklevel=4)
+        return None
+    dp = tuple(ax for ax in a["dp"] if ax in mesh.axis_names)
+    if batch % math.prod(mesh.shape[ax] for ax in dp):
+        dp = ()
+    return dp, tp
+
+
+def _shard_heads(core, dp, tp):
+    """``core`` (``_self_attend``'s arguments) as one ``shard_map`` with
+    the KV heads over ``tp`` and the batch over the axes ``dp``: each chip
+    computes its own heads, and no collective runs inside attention."""
+    b = dp or None
+
+    def call(q, k, v, positions, slopes, norms):
+        pos = (P(None, b, None) if positions is not None
+               and positions.ndim == 3 else P(b, None))
+        return jax.shard_map(
+            core, axis_names={tp, *dp},
+            in_specs=(P(b, None, tp, None, None), P(b, None, tp, None),
+                      P(b, None, tp, None), pos, P(tp, None), P()),
+            out_specs=P(b, None, tp, None, None),
+        )(q, k, v, positions, slopes, norms)
+
+    return call
+
+
 def attention(p: Params, cfg, x: jnp.ndarray, positions, *,
               cache: Optional[Params] = None, x_kv: Optional[jnp.ndarray] = None,
               causal: bool = True, kv_block: int = 1024,
@@ -230,6 +336,11 @@ def attention(p: Params, cfg, x: jnp.ndarray, positions, *,
     * ``cache`` given -> decode: x is (B,1,D); KV appended into the cache
       (ring buffer when cfg.sliding_window > 0).
     * ``x_kv`` given  -> cross attention (no cache update of x_kv side).
+
+    Self-attention without a cache runs head-parallel on a mesh whose
+    model axis divides the KV heads (``_head_parallel_axes``): RoPE, the
+    mask and the core in one ``shard_map``, between the column-sharded
+    q/k/v and the row-sharded o projections.
     """
     B, Sq, _ = x.shape
     N, G, h = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim
@@ -238,73 +349,51 @@ def attention(p: Params, cfg, x: jnp.ndarray, positions, *,
     q = linear(p["q"], x).reshape(B, Sq, N, G, h)
     k = linear(p["k"], src).reshape(B, src.shape[1], N, h)
     v = linear(p["v"], src).reshape(B, src.shape[1], N, h)
+    is_cross = x_kv is not None
+
+    if cache is None or is_cross:
+        causal = causal and not is_cross
+        rope = not is_cross and cfg.pos_kind in ("rope", "mrope")
+        core = partial(_self_attend, cfg=cfg, causal=causal, rope=rope,
+                       kv_block=kv_block,
+                       blockwise_threshold=blockwise_threshold)
+        slopes = (alibi_slopes(cfg.num_heads).reshape(N, G)
+                  if causal and cfg.pos_kind == "alibi" else None)
+        norms = {n: p[n] for n in ("q_norm", "k_norm") if n in p}
+        positions = positions if rope or causal else None
+        layout = None if is_cross else _head_parallel_axes(N, B)
+        if layout is None:
+            out = core(q, k, v, positions, slopes, norms)
+            return linear(p["o"], out.reshape(B, Sq, N * G * h)), None
+        out = _shard_heads(core, *layout)(q, k, v, positions, slopes, norms)
+        # the o projection's partial sums are reduced into the layer
+        # boundary's layout, so its cotangent arrives whole and the weight
+        # gradients need no gather of the (B, S, D) activations
+        return CT.btd(linear(p["o"], out.reshape(B, Sq, N * G * h))), None
+
+    # ---- decode / cached prefill: append this step's K/V ------------------
+    # Sq == 1 is the decode step; Sq > 1 is prefill-into-cache (only valid
+    # for SWA when the whole segment fits the ring without wrap).
     if cfg.qk_norm:
         q = norm(p["q_norm"], q, "rmsnorm")
         k = norm(p["k_norm"], k, "rmsnorm")
-
-    scale = 1.0 / math.sqrt(h)
-    is_cross = x_kv is not None
-    new_cache = None
-
-    if cfg.pos_kind == "rope" or cfg.pos_kind == "mrope":
-        if not is_cross:
-            qr = q.reshape(B, Sq, N * G, h)
-            qr, k = apply_rope(qr, k, positions, head_dim=h,
-                               fraction=cfg.rope_fraction, theta=cfg.rope_theta,
-                               mrope_sections=cfg.mrope_sections if cfg.pos_kind == "mrope" else ())
-            q = qr.reshape(B, Sq, N, G, h)
-
-    if cache is not None and not is_cross:
-        # ---- decode / cached prefill: append this step's K/V --------------
-        # Sq == 1 is the decode step; Sq > 1 is prefill-into-cache (only
-        # valid for SWA when the whole segment fits the ring without wrap).
-        W = cache["k"].shape[1]
-        t = cache["pos"]                       # scalar int32: tokens so far
-        slot = t % W if cfg.sliding_window else t
-        ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-        cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-        # slot_pos is per-sequence (B, W): serving engines invalidate each
-        # row's right-padded prefill slots independently (slot_pos = -1)
-        row = jnp.broadcast_to(t + jnp.arange(Sq, dtype=jnp.int32)[None, :], (B, Sq))
-        spos = lax.dynamic_update_slice(cache["slot_pos"], row, (0, slot))
-        new_cache = {"k": ck, "v": cv, "pos": t + Sq, "slot_pos": spos}
-        k, v = ck, cv
-        q_pos = t + jnp.arange(Sq)                                # (Sq,)
-        valid = (spos[:, None, :] >= 0) & (spos[:, None, :] <= q_pos[None, :, None])
-        if cfg.sliding_window:
-            valid &= spos[:, None, :] > q_pos[None, :, None] - cfg.sliding_window
-        bias = jnp.where(valid, 0.0, NEG_INF)[:, None, None, :, :]
-        out = _gqa_scores_to_out(q, k, v, bias, scale)
-    else:
-        Sk = k.shape[1]
-        if is_cross or not causal:
-            def bias_fn(q0, qlen, s0, slen):
-                return jnp.zeros((1, 1, 1, 1, slen), jnp.float32)
-        else:
-            q_pos_full = positions if positions.ndim == 2 else positions[0]
-            padq = (-Sq) % Q_BLOCK
-            if padq:        # bias_fn may be sliced from padded query blocks
-                q_pos_full = jnp.pad(q_pos_full, ((0, 0), (0, padq)))
-
-            def bias_fn(q0, qlen, s0, slen):
-                q_pos = lax.dynamic_slice_in_dim(q_pos_full, q0, qlen, axis=1)
-                kpos = s0 + jnp.arange(slen)
-                m = q_pos[:, :, None] >= kpos[None, None, :]
-                if cfg.sliding_window:
-                    m &= q_pos[:, :, None] - kpos[None, None, :] < cfg.sliding_window
-                b = jnp.where(m, 0.0, NEG_INF)            # (B,qlen,slen)
-                b = b[:, None, None, :, :]
-                if cfg.pos_kind == "alibi":
-                    slopes = alibi_slopes(cfg.num_heads).reshape(1, N, G, 1, 1)
-                    dist = (kpos[None, None, :] - q_pos[:, :, None]).astype(jnp.float32)
-                    b = b + slopes * dist[:, None, None, :, :]
-                return b
-
-        if Sk > blockwise_threshold or Sq * Sk > blockwise_threshold ** 2:
-            out = _blockwise_attention(q, k, v, bias_fn, scale, kv_block)
-        else:
-            out = _gqa_scores_to_out(q, k, v, bias_fn(0, Sq, 0, Sk), scale)
-
+    q, k = _rope_qk(cfg, q, k, positions)
+    W = cache["k"].shape[1]
+    t = cache["pos"]                       # scalar int32: tokens so far
+    slot = t % W if cfg.sliding_window else t
+    ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
+    cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
+    # slot_pos is per-sequence (B, W): serving engines invalidate each row's
+    # right-padded prefill slots independently (slot_pos = -1)
+    row = jnp.broadcast_to(t + jnp.arange(Sq, dtype=jnp.int32)[None, :], (B, Sq))
+    spos = lax.dynamic_update_slice(cache["slot_pos"], row, (0, slot))
+    new_cache = {"k": ck, "v": cv, "pos": t + Sq, "slot_pos": spos}
+    q_pos = t + jnp.arange(Sq)                                    # (Sq,)
+    valid = (spos[:, None, :] >= 0) & (spos[:, None, :] <= q_pos[None, :, None])
+    if cfg.sliding_window:
+        valid &= spos[:, None, :] > q_pos[None, :, None] - cfg.sliding_window
+    bias = jnp.where(valid, 0.0, NEG_INF)[:, None, None, :, :]
+    out = _gqa_scores_to_out(q, ck, cv, bias, 1.0 / math.sqrt(h))
     out = out.reshape(B, Sq, N * G * h)
     return linear(p["o"], out), new_cache
 

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e (no chip attached): every Pallas kernel
-at the widths of a zoo model, and the 4-layer h2o-danube-1.8b train step
-that ``chip_smoke.py`` runs, which must fit one chip's 16 GB.
+at the widths of a zoo model, the 4-layer h2o-danube-1.8b train step that
+``chip_smoke.py`` runs, which must fit one chip's 16 GB, and a step with
+yi-34b's attention on a 1x4 mesh, which must run head-parallel.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs these tests loads the TPU compiler.  JAX's persistent
@@ -105,3 +106,69 @@ def test_h2o_4layer_train_step_fits_one_v5e(one_chip):
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert m.alias_size_in_bytes > 0            # the state is donated
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB"
+
+
+def test_yi_attention_runs_head_parallel_on_v5e_2x2(topo):
+    """A 1-layer step with yi-34b's attention (56/8 heads of 128, seq 4096)
+    under a tuned ``tp:4`` plan on a 1x4 mesh: every chip holds 2 of the 8
+    KV heads, and no collective runs inside attention's loops.  The other
+    widths are small: the head layout is what is checked."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.core import extract_workload, tune
+    from repro.core.extract import parse_parallel
+    from repro.models import model as M
+    from repro.optim import adamw
+    from repro.parallel import constraints as CT
+    from repro.parallel import sharding as SH
+    from repro.train.trainer import TrainConfig, jit_train_step
+
+    cfg = get_config("yi-34b").replace(num_layers=1, d_model=512, d_ff=1024,
+                                       vocab_size=1024, dtype="float32")
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (56, 8, 128)
+    B, S = 1, 4096
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    plan = tune(extract_workload(cfg, parse_parallel("tp:4"), seq=S,
+                                 global_batch=B), "tpu-v5e", seed=0)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.set_mesh(mesh), CT.use_axes(("data",), "model"), \
+                plan.applied():
+            shapes = jax.eval_shape(
+                lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+            shard = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 SH.param_specs(shapes, mesh))
+            params = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=s), shapes, shard)
+            opt = {"mu": params, "nu": params,
+                   "count": jax.ShapeDtypeStruct((), jnp.int32)}
+            batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
+                     "targets": jax.ShapeDtypeStruct((B, S), jnp.int32),
+                     "mask": _f32(B, S)}
+            tcfg = dataclasses.replace(TrainConfig(total_steps=10),
+                                       sited_mesh=mesh)
+            hlo = jit_train_step(cfg, tcfg, params, opt).lower(
+                params, opt, batch, jax.ShapeDtypeStruct((), jnp.int32)
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+    in_loops = [
+        line.strip()[:160] for line in hlo.splitlines()
+        if re.search(r"\b(all-gather|reduce-scatter|all-reduce|all-to-all)"
+                     r"(-start)?\(", line)
+        and re.search(r'op_name="[^"]*attention[^"]*/while', line)]
+    assert not in_loops, in_loops
+    # score blocks (batch, KV heads, group of 7, 512 queries, 1024 keys)
+    assert "f32[1,2,7,512,1024]" in hlo
+    assert "f32[1,8,7,512,1024]" not in hlo
