@@ -297,10 +297,57 @@ def ag_matmul_ref(x, w):
     return x @ w
 
 
+def _weight_grad(xs, gs, w):
+    """``dw`` of ``xs @ w`` as one dot over every leading and row dim of
+    ``xs`` (``(..., K)``) and ``gs`` (``(..., F)``): a single ``(K, F)``
+    product instead of one per chunk summed into an accumulator."""
+    dt = jnp.result_type(xs, gs)
+    lead = tuple(range(xs.ndim - 1))
+    dw = lax.dot_general(xs.astype(dt), gs.astype(dt), ((lead, lead), ((), ())))
+    return dw.astype(w.dtype)
+
+
+def _vary_like(vma, *arrays):
+    """``arrays`` cast to vary over every manual axis in ``vma``, as the
+    matmul's implicit cast would.  Done before a ``custom_vjp`` so that
+    its weight cotangent has the weight's own type; the cast's transpose
+    then sums over the added axes once, outside the chunk loop."""
+    out = []
+    for a in arrays:
+        extra = tuple(ax for ax in vma if ax not in jax.typeof(a).vma)
+        out.append(lax.pcast(a, extra, to="varying") if extra else a)
+    return out
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _chunked_matmul(xs, w, num_chunks):
+    """``xs @ w`` as ``num_chunks`` row blocks in a ``lax.map``, so the
+    ring's next ``ppermute`` overlaps the blocks' matmuls.  Its backward
+    forms ``dw`` once over all of ``xs``'s rows, not once per block."""
+    blocks = jnp.stack(jnp.split(xs, num_chunks, axis=-2))
+    ys = lax.map(lambda b: b @ w, blocks)
+    return jnp.concatenate(list(ys), axis=-2)
+
+
+def _chunked_matmul_fwd(xs, w, num_chunks):
+    return _chunked_matmul(xs, w, num_chunks), (xs, w)
+
+
+def _chunked_matmul_bwd(num_chunks, res, g):
+    xs, w = res
+    gb = jnp.stack(jnp.split(g, num_chunks, axis=-2))
+    dxb = lax.map(lambda b: (b @ w.T).astype(xs.dtype), gb)
+    return jnp.concatenate(list(dxb), axis=-2), _weight_grad(xs, g, w)
+
+
+_chunked_matmul.defvjp(_chunked_matmul_fwd, _chunked_matmul_bwd)
+
+
 def _ring_ag_matmul_local(x, w, *, axis: str, num_chunks: int, site: str = "ag"):
     """Per-device body: hold one sequence shard, rotate shards around the
     ring; each step multiplies the currently-held shard so communication of
-    the next shard overlaps with this step's matmul."""
+    the next shard overlaps with this step's matmul.  The backward forms
+    the weight-shard gradient once per held shard (``_chunked_matmul``)."""
     n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     Tl = x.shape[-2]
@@ -311,23 +358,19 @@ def _ring_ag_matmul_local(x, w, *, axis: str, num_chunks: int, site: str = "ag")
     if num_chunks > 1 and not chunked:
         _warn_unchunked(site, num_chunks, f"the local sequence shard ({Tl})")
 
-    def chunked_mm(xs):
-        if not chunked:
-            return xs @ w
-        blocks = jnp.stack(jnp.split(xs, num_chunks, axis=-2))
-        ys = lax.map(lambda b: b @ w, blocks)
-        return jnp.concatenate(list(ys), axis=-2)
+    # the accumulator varies over the same manual axes as the inputs
+    vma = tuple(set(jax.typeof(x).vma) | set(jax.typeof(w).vma))
+    if chunked:
+        x, w = _vary_like(vma, x, w)
 
     def body(i, carry):
         x_cur, out = carry
         src = (idx + i) % n                 # whose shard we currently hold
-        y = chunked_mm(x_cur)
+        y = _chunked_matmul(x_cur, w, num_chunks) if chunked else x_cur @ w
         out = lax.dynamic_update_slice_in_dim(out, y, src * Tl, axis=-2)
         x_cur = lax.ppermute(x_cur, axis, perm)
         return (x_cur, out)
 
-    # the accumulator varies over the same manual axes as the inputs
-    vma = tuple(set(jax.typeof(x).vma) | set(jax.typeof(w).vma))
     out = lax.pcast(jnp.zeros(out_shape, x.dtype), vma, to="varying")
     _, out = lax.fori_loop(0, n, body, (x, out))
     return out
@@ -355,6 +398,41 @@ def mm_rs_ref(x, w):
     return x @ w
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _chunked_mm_rs(blocks, w, axis):
+    """Each row block's ``b @ w`` reduce-scattered over ``axis`` in a
+    ``lax.map``, so the scatter of block i overlaps the matmul of i+1.  Its
+    backward gathers each block's cotangent in a ``lax.map`` beside that
+    block's ``dx`` matmul, and forms ``dw`` once from all the blocks."""
+    def one(b):
+        y = b @ w
+        return lax.psum_scatter(y, axis, scatter_dimension=y.ndim - 2, tiled=True)
+
+    return lax.map(one, blocks)
+
+
+def _chunked_mm_rs_fwd(blocks, w, axis):
+    return _chunked_mm_rs(blocks, w, axis), (blocks, w)
+
+
+def _chunked_mm_rs_bwd(axis, res, gys):
+    blocks, w = res
+
+    def one(gy):
+        g = lax.all_gather(gy, axis, axis=gy.ndim - 2, tiled=True)
+        return g, (g @ w.T).astype(blocks.dtype)
+
+    gs, dblocks = lax.map(one, gys)
+    # dw feeds only the optimizer, so XLA would defer it past the earlier
+    # layers' backward and keep every layer's blocks and gathered
+    # cotangents live (2.6 GB more on a 4-layer h2o-danube-1.8b step); the
+    # barrier makes it complete before dx flows on
+    return lax.optimization_barrier((dblocks, _weight_grad(blocks, gs, w)))
+
+
+_chunked_mm_rs.defvjp(_chunked_mm_rs_fwd, _chunked_mm_rs_bwd)
+
+
 def _mm_rs_local(x, w, *, axis: str, num_chunks: int, site: str = "rs"):
     n = lax.axis_size(axis)
     T = x.shape[-2]
@@ -373,11 +451,9 @@ def _mm_rs_local(x, w, *, axis: str, num_chunks: int, site: str = "rs"):
     blocks = jnp.moveaxis(xr, -3, 0)                     # (nc, ..., n, s, F)
     blocks = blocks.reshape((num_chunks,) + lead + (n * s, x.shape[-1]))
 
-    def one(b):
-        y = b @ w
-        return lax.psum_scatter(y, axis, scatter_dimension=y.ndim - 2, tiled=True)
-
-    ys = lax.map(one, blocks)        # chunked: scatter of chunk i overlaps mm of i+1
+    vma = tuple(set(jax.typeof(x).vma) | set(jax.typeof(w).vma))
+    blocks, w = _vary_like(vma, blocks, w)
+    ys = _chunked_mm_rs(blocks, w, axis)
     return jnp.concatenate(list(ys), axis=-2)
 
 
